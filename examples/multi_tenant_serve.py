@@ -64,9 +64,9 @@ def main():
     ap.add_argument("--admission", default="interleaved",
                     choices=["interleaved", "sequential"])
     ap.add_argument("--devices", type=int, default=0,
-                    help="fleet mode: split the host into N XLA devices and "
-                         "serve the workload over N replica chips, each with "
-                         "its own CaMDN allocator")
+                    help="fleet mode: serve the workload over N replica "
+                         "chips (on the CPU backend, N host devices), each "
+                         "with its own CaMDN allocator")
     args = ap.parse_args()
 
     arrivals = [

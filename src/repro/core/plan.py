@@ -27,9 +27,10 @@ from typing import Optional, Tuple
 
 from repro.core.allocator import Selection
 from repro.core.vmem import (LANE, PAGE_BYTES, TileConfig,
+                             attn_vmem_bytes, fused_block_fs,
                              fused_ffn_block_s, fused_ffn_vmem_bytes,
-                             lower_matmul_tile, min_fused_block_f,
-                             prefill_chunk_tokens)
+                             lower_matmul_tile, prefill_chunk_tokens,
+                             ssd_chunk_vmem_bytes)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,17 +90,15 @@ def lower_ffn(seq_block: int, d_model: int, d_ff: int, dtype_bytes: int,
               pages: int, want_fused: bool,
               down_pages: Optional[int] = None) -> FfnPlan:
     """Lower one FFN under a page grant.  LBM is taken only when the
-    candidate asked for it AND some legal fused block shape (a divisor
-    of d_ff, no smaller than min_fused_block_f) fits the grant — the
+    candidate asked for it AND some legal fused block shape
+    (:func:`~repro.core.vmem.fused_block_fs`) fits the grant — the
     same formula and floor fused_ffn_pages quotes, so an admitted LBM
     grant always lowers fused.  Otherwise each GEMM gets the best tile
     fitting its own grant."""
     if want_fused:
         bs = fused_ffn_block_s(seq_block, dtype_bytes)
         cap = pages * PAGE_BYTES
-        for bf in range(min(d_ff, 1024), min_fused_block_f(d_ff) - 1, -1):
-            if d_ff % bf:
-                continue
+        for bf in fused_block_fs(d_ff):
             vb = fused_ffn_vmem_bytes(bs, bf, d_model, dtype_bytes)
             if vb <= cap:
                 return FfnPlan(fused=True, block_s=bs, block_f=bf,
@@ -122,14 +121,11 @@ def lower_attn(head_dim: int, dtype_bytes: int, pages: int,
     if head_dim <= 0:
         return AttnPlan(kv_dtype=kv_dtype)
     kvb = dtype_bytes if kv_dtype_bytes is None else kv_dtype_bytes
-    scale = 4 if kvb < dtype_bytes else 0  # fp32 scale per streamed row
     cap = pages * PAGE_BYTES
     best = (LANE, LANE)
     for bq in (128, 256, 512):
         for bkv in (128, 256, 512):
-            vb = (bq * head_dim * dtype_bytes
-                  + 4 * bkv * (head_dim * kvb + scale)
-                  + bq * head_dim * 4 + bq * bkv * 4)
+            vb = attn_vmem_bytes(bq, bkv, head_dim, dtype_bytes, kvb)
             if vb <= cap and bq * bkv > best[0] * best[1]:
                 best = (bq, bkv)
     return AttnPlan(*best, kv_dtype=kv_dtype)
@@ -142,7 +138,7 @@ def lower_ssm_chunk(default_chunk: int, pages: int) -> int:
         return 0
     cap = pages * PAGE_BYTES
     c = default_chunk
-    while c > 64 and 12 * c * c > cap:
+    while c > 64 and ssd_chunk_vmem_bytes(c) > cap:
         c //= 2
     return max(c, min(64, default_chunk))
 

@@ -22,13 +22,31 @@ from repro.core.types import align_up, ceil_div
 
 LANE = 128
 PAGE_BYTES = 32 * 2**10
-# v5e has ~128 MiB of VMEM per core usable by Pallas; XLA reserves a slice.
+# v5e has 128 MiB of VMEM per TensorCore; XLA reserves a slice.
+CHIP_VMEM_BYTES = 128 * 2**20
 VMEM_BYTES = 96 * 2**20
 VMEM_PAGES = VMEM_BYTES // PAGE_BYTES
+# Mosaic's scoped-VMEM limit when a pallas_call sets none (v5e).
+DEFAULT_SCOPED_VMEM_BYTES = 16 * 2**20
 
 
 def sublane(dtype_bytes: int) -> int:
     return {4: 8, 2: 16, 1: 32}.get(dtype_bytes, 8)
+
+
+def vmem_limit_bytes(claim: int) -> int:
+    """Scoped-VMEM limit for a ``pallas_call`` whose modeled working set
+    is ``claim`` bytes — the same claim the plan lowering bills in
+    pages.  Twice the claim leaves room for what the model omits
+    (Mosaic's output double buffer and internal scratch); never below
+    the default limit, never above the chip's VMEM."""
+    return min(CHIP_VMEM_BYTES, max(DEFAULT_SCOPED_VMEM_BYTES, 2 * claim))
+
+
+def compiler_params(claim: int):
+    """``pltpu.CompilerParams`` carrying :func:`vmem_limit_bytes`."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes(claim))
 
 
 # Per-row quantization scale width: one fp32 scale per (token, kv-head)
@@ -68,6 +86,23 @@ def tile_vmem_bytes(bm: int, bn: int, bk: int, dtype_bytes: int,
     """Working set of one (bm,bn,bk) matmul tile: A+B double-buffered in
     dtype, C accumulator in fp32."""
     return 2 * (bm * bk + bk * bn) * dtype_bytes + bm * bn * acc_bytes
+
+
+def attn_vmem_bytes(block_q: int, block_kv: int, head_dim: int,
+                    dtype_bytes: int, kv_dtype_bytes: int) -> int:
+    """Working set of one flash-attention grid step: the q tile, k/v
+    double buffers (plus the fp32 per-row scale stripe a quantized K/V
+    streams), the fp32 accumulator and the fp32 score tile."""
+    scale = 4 if kv_dtype_bytes < dtype_bytes else 0
+    return (block_q * head_dim * dtype_bytes
+            + 4 * block_kv * (head_dim * kv_dtype_bytes + scale)
+            + block_q * head_dim * 4 + block_q * block_kv * 4)
+
+
+def ssd_chunk_vmem_bytes(chunk: int) -> int:
+    """Quadratic working set of one SSD intra-chunk step: the fp32
+    decay, score and weight tiles ([chunk, chunk] each)."""
+    return 12 * chunk * chunk
 
 
 def candidates_for_matmul(m: int, n: int, k: int, dtype_bytes: int,
@@ -114,14 +149,19 @@ def fused_ffn_block_s(seq_block: int, dtype_bytes: int) -> int:
     return min(2 * LANE, align_up(max(seq_block, sl), sl))
 
 
+def fused_block_fs(d_ff: int, cap: int = 1024) -> List[int]:
+    """Legal fused-FFN d_ff blocks, largest first: block_fused_ffn needs
+    a divisor of d_ff, and Mosaic needs the block's lane dimension to be
+    a multiple of LANE or the whole axis — so the LANE-multiple divisors
+    up to ``cap``, or d_ff itself when it has none."""
+    out = [bf for bf in range(min(d_ff, cap) // LANE * LANE, 0, -LANE)
+           if d_ff % bf == 0]
+    return out or [d_ff]
+
+
 def min_fused_block_f(d_ff: int) -> int:
-    """Smallest legal fused-FFN d_ff block: block_fused_ffn requires a
-    divisor of d_ff, and below one lane the MXU utilization collapses —
-    so the largest divisor <= LANE."""
-    for x in range(min(d_ff, LANE), 0, -1):
-        if d_ff % x == 0:
-            return x
-    return d_ff
+    """Smallest legal fused-FFN d_ff block (see :func:`fused_block_fs`)."""
+    return fused_block_fs(d_ff)[-1]
 
 
 def fused_ffn_vmem_bytes(block_s: int, block_f: int, d_model: int,

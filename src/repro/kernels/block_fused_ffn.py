@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.vmem import compiler_params, fused_ffn_vmem_bytes
+
 
 def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *, n_f: int):
     fi = pl.program_id(1)
@@ -63,5 +65,7 @@ def block_fused_ffn(x: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
         out_specs=pl.BlockSpec((bs, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bs, d), jnp.float32)],
+        compiler_params=compiler_params(
+            fused_ffn_vmem_bytes(bs, bf, d, x.dtype.itemsize)),
         interpret=interpret,
     )(x, wg, wu, wd)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.vmem import TileConfig
+from repro.core.vmem import TileConfig, compiler_params, tile_vmem_bytes
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -81,6 +81,8 @@ def cache_matmul_quant(a: jnp.ndarray, b: jnp.ndarray, b_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=compiler_params(
+            tile_vmem_bytes(bm, bn, bk, a.dtype.itemsize)),
         interpret=interpret,
     )(a, b, b_scale.astype(jnp.float32))
 
@@ -106,5 +108,7 @@ def cache_matmul(a: jnp.ndarray, b: jnp.ndarray, tile: TileConfig,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=compiler_params(
+            tile_vmem_bytes(bm, bn, bk, a.dtype.itemsize)),
         interpret=interpret,
     )(a, b)

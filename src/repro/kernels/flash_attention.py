@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.vmem import attn_vmem_bytes, compiler_params
+
 NEG_INF = -1e30
 
 
@@ -124,6 +126,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),      # running sum
             pltpu.VMEM((bq, hd), jnp.float32),   # accumulator
         ],
+        compiler_params=compiler_params(attn_vmem_bytes(
+            bq, bkv, hd, q.dtype.itemsize, k.dtype.itemsize)),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, H, S, hd)
@@ -189,6 +193,8 @@ def flash_attention_quantized(q: jnp.ndarray, k: jnp.ndarray,
             pltpu.VMEM((bq,), jnp.float32),      # running sum
             pltpu.VMEM((bq, hd), jnp.float32),   # accumulator
         ],
+        compiler_params=compiler_params(attn_vmem_bytes(
+            bq, bkv, hd, q.dtype.itemsize, k.dtype.itemsize)),
         interpret=interpret,
     )(qr, kr, vr, ksr, vsr)
     return out.reshape(B, H, S, hd)

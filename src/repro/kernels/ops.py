@@ -1,7 +1,12 @@
 """jit'd public wrappers for the Pallas kernels: padding to tile
 boundaries, budget-driven tile selection (the CaMDN candidate bridge),
 KernelPlan dispatch (the grant -> kernel execution link), and the
-interpret-mode switch (CPU validation vs TPU execution)."""
+interpret-mode switch (CPU validation vs TPU execution).
+
+Every wrapper takes ``interpret=None``, which resolves when the wrapper
+is traced: compiled Mosaic kernels on a TPU backend, the Pallas
+interpreter anywhere else.  Pass ``interpret=False`` to compile for a
+TPU that is described but not attached."""
 from __future__ import annotations
 
 import functools
@@ -19,8 +24,10 @@ from repro.kernels.flash_attention import (flash_attention,
                                            flash_attention_quantized)
 from repro.kernels.ssd_scan import ssd_chunk
 
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-INTERPRET = not ON_TPU
+
+def _interpret(flag: Optional[bool]) -> bool:
+    """Resolve a wrapper's ``interpret`` argument at trace time."""
+    return jax.default_backend() != "tpu" if flag is None else flag
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -35,7 +42,7 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def planned_matmul(a: jnp.ndarray, b: jnp.ndarray, tile: TileConfig,
-                   interpret: bool = INTERPRET) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """Matmul through an explicit, already-lowered tile — the KernelPlan
     dispatch point: the tile comes from the allocator's grant via
     core/plan.lower_selection, not from local re-enumeration."""
@@ -43,13 +50,13 @@ def planned_matmul(a: jnp.ndarray, b: jnp.ndarray, tile: TileConfig,
     _, n = b.shape
     ap = _pad_to(_pad_to(a, 0, tile.bm), 1, tile.bk)
     bp = _pad_to(_pad_to(b, 0, tile.bk), 1, tile.bn)
-    out = cache_matmul(ap, bp, tile, interpret=interpret)
+    out = cache_matmul(ap, bp, tile, interpret=_interpret(interpret))
     return out[:m, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "interpret"))
 def budgeted_matmul(a: jnp.ndarray, b: jnp.ndarray, pages: int = 64,
-                    interpret: bool = INTERPRET) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Matmul through the tile candidate selected for a page budget."""
     m, k = a.shape
     _, n = b.shape
@@ -60,7 +67,7 @@ def budgeted_matmul(a: jnp.ndarray, b: jnp.ndarray, pages: int = 64,
 @functools.partial(jax.jit, static_argnames=("plan", "interpret"))
 def planned_ffn(x: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
                 wd: jnp.ndarray, plan: FfnPlan,
-                interpret: bool = INTERPRET) -> jnp.ndarray:
+                interpret: Optional[bool] = None) -> jnp.ndarray:
     """SwiGLU FFN executed the way the plan's candidate prescribes:
 
       LBM (plan.fused)  -> block_fused_ffn; the hidden activation never
@@ -83,7 +90,7 @@ def planned_ffn(x: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def planned_matmul_quant(a: jnp.ndarray, b: jnp.ndarray,
                          b_scale: jnp.ndarray, tile: TileConfig,
-                         interpret: bool = INTERPRET) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """Dequant-fused planned matmul: ``b`` pre-quantized (int8/fp8)
     with per-column scales ``b_scale`` [1, N] (kernels.quant
     .quantize_cols).  The B operand streams at quantized width through
@@ -93,13 +100,13 @@ def planned_matmul_quant(a: jnp.ndarray, b: jnp.ndarray,
     ap = _pad_to(_pad_to(a, 0, tile.bm), 1, tile.bk)
     bp = _pad_to(_pad_to(b, 0, tile.bk), 1, tile.bn)
     sp = _pad_to(b_scale, 1, tile.bn)
-    out = cache_matmul_quant(ap, bp, sp, tile, interpret=interpret)
+    out = cache_matmul_quant(ap, bp, sp, tile, interpret=_interpret(interpret))
     return out[:m, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "interpret"))
 def planned_ffn_quant(x: jnp.ndarray, wg, wg_s, wu, wu_s, wd, wd_s,
-                      plan: FfnPlan, interpret: bool = INTERPRET
+                      plan: FfnPlan, interpret: Optional[bool] = None
                       ) -> jnp.ndarray:
     """SwiGLU FFN over pre-quantized weights (per-column scales), each
     GEMM through the dequant-fused tiled kernel with the plan's tiles.
@@ -120,7 +127,7 @@ def planned_ffn_quant(x: jnp.ndarray, wg, wg_s, wu, wu_s, wd, wd_s,
                                              "kv_dtype", "interpret"))
 def attention(q, k, v, causal: bool = True, block_q: int = 128,
               block_kv: int = 128, kv_dtype: str = "native",
-              interpret: bool = INTERPRET):
+              interpret: Optional[bool] = None):
     """Flash attention; ``kv_dtype`` != "native" quantizes K/V per row
     and runs the dequant-fused kernel (the plan-lowered prefill path of
     a precision-downgraded tenant)."""
@@ -135,27 +142,27 @@ def attention(q, k, v, causal: bool = True, block_q: int = 128,
         vq, vs = kquant.quantize_rows(vp, kv_dtype)
         out = flash_attention_quantized(
             qp, kq, vq, ks[..., 0], vs[..., 0], causal=causal,
-            block_q=bq, block_kv=bkv, interpret=interpret)
+            block_q=bq, block_kv=bkv, interpret=_interpret(interpret))
         return out[:, :, :S, :]
     out = flash_attention(qp, kp, vp, causal=causal, block_q=bq,
-                          block_kv=bkv, interpret=interpret)
+                          block_kv=bkv, interpret=_interpret(interpret))
     return out[:, :, :S, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_f",
                                              "interpret"))
 def fused_ffn(x, wg, wu, wd, block_s: int = 256, block_f: int = 512,
-              interpret: bool = INTERPRET):
+              interpret: Optional[bool] = None):
     S = x.shape[0]
     bs = min(block_s, S)
     xp = _pad_to(x, 0, bs)
     out = block_fused_ffn(xp, wg, wu, wd, block_s=bs,
                           block_f=min(block_f, wg.shape[1]),
-                          interpret=interpret)
+                          interpret=_interpret(interpret))
     return out[:S]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_intra_chunk(x, dt, A, B, C, chunk: int = 256,
-                    interpret: bool = INTERPRET):
-    return ssd_chunk(x, dt, A, B, C, chunk, interpret=interpret)
+                    interpret: Optional[bool] = None):
+    return ssd_chunk(x, dt, A, B, C, chunk, interpret=_interpret(interpret))

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.vmem import compiler_params, ssd_chunk_vmem_bytes
+
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
                       y_ref, state_ref, *, chunk: int):
@@ -77,6 +79,7 @@ def ssd_chunk(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
             jax.ShapeDtypeStruct((BH, S, P), jnp.float32),
             jax.ShapeDtypeStruct((BH, n_c, N, P), jnp.float32),
         ],
+        compiler_params=compiler_params(ssd_chunk_vmem_bytes(chunk)),
         interpret=interpret,
     )(x, dt[..., None], A[:, None], B, C)
     return y, states

@@ -12,7 +12,10 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_BF16_FLOPS
+from repro.launch.mesh import peaks
+
+# The dry-run's production mesh is v5e (launch/mesh.py).
+_V5E = peaks("TPU v5 lite")
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -69,9 +72,10 @@ class Roofline:
     hbm_bytes: float           # per-device bytes accessed
     coll_bytes: float          # per-device collective bytes
     coll_breakdown: Dict[str, int]
-    peak_flops: float = PEAK_BF16_FLOPS
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW * 4  # ~4 usable links per chip on a 2-D torus
+    peak_flops: float = _V5E["bf16_flops"]
+    hbm_bw: float = _V5E["hbm_bw"]
+    # ~4 usable links per chip on a 2-D torus
+    ici_bw: float = _V5E["ici_link_bw"] * 4
 
     @property
     def compute_s(self) -> float:

@@ -16,16 +16,29 @@ topologies are testable without accelerators.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-# v5e hardware constants (roofline terms, benchmarks/roofline.py)
-PEAK_BF16_FLOPS = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (~4 links usable / chip)
+# Per-chip peaks (roofline terms, launch/hlo_analysis.py), keyed by
+# ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect (4 links of 50 GB/s).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9,
+                    "hbm_bytes": 16e9, "ici_link_bw": 50e9},
+}
 CHIPS_PER_POD = 256
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table row for one device kind; an unknown kind is an
+    error, never a silent v5e default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"have {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,8 +52,12 @@ def make_host_mesh():
     production mesh).  Sized from :func:`jax.device_count` — under
     ``--xla_force_host_platform_device_count=N`` this is a real
     (N, 1) data-parallel mesh; on a stock single-device host it
-    degrades to the old (1, 1) fallback."""
-    return jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    degrades to the old (1, 1) fallback.  Its axes are Auto, as a
+    ``Mesh`` built directly has them (:func:`make_serving_mesh`):
+    ``shard_hint`` constrains through ``with_sharding_constraint``,
+    which refuses the Explicit axes ``jax.make_mesh`` defaults to."""
+    return jax.make_mesh((jax.device_count(), 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serving_mesh(n_replicas: Optional[int] = None, tp: int = 1,

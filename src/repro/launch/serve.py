@@ -80,8 +80,10 @@ the pipelined loop and it is the baseline the serving benchmark
 (``benchmarks/run.py`` -> ``BENCH_serve.json``) measures speedup
 against.
 
-On CPU this runs reduced models with the interpret-mode kernels; on TPU
-the same loop binds to the compiled kernel variants.  The allocation
+A tenant spec that names an arch id serves that arch's reduced CPU
+variant; a spec carrying an ArchConfig serves it exactly as given
+(:func:`resolve_arch`).  The Pallas kernels run compiled on a TPU
+backend and in interpret mode elsewhere (kernels/ops.py).  The allocation
 trace (who held how many pages, which candidates ran, bypass decisions)
 is the serving-side reproduction of the paper's runtime behaviour.
 """
@@ -97,7 +99,7 @@ import tempfile
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -142,6 +144,23 @@ def _elem_bytes(cfg: ArchConfig) -> int:
     return elem_bytes(cfg.dtype)
 
 
+def resolve_arch(model: Union[str, ArchConfig]) -> ArchConfig:
+    """The config a tenant spec's ``model`` names.  An ArchConfig is
+    served exactly as given (published widths, depth cut by
+    :meth:`~repro.models.base.ArchConfig.depth_cut`); a registered arch
+    id resolves to its reduced CPU variant."""
+    if isinstance(model, ArchConfig):
+        return model
+    return get_arch(model).reduced()
+
+
+def _ffn_width(cfg: ArchConfig) -> int:
+    """d_ff of the layer's schedulable GEMM block.  Archs without a dense
+    FFN (SSM, d_ff 0) schedule their projections as a d_model-wide
+    block, so the LBM quote and the plan lowering never see d_ff 0."""
+    return max(cfg.d_ff, cfg.d_model)
+
+
 def _ffn_graph(name: str, cfg: ArchConfig, seq_block: int) -> ModelGraph:
     """One transformer layer's FFN as a schedulable layer graph
     (gate/up -> down), so the core mapper derives its MCTs — LWM tile
@@ -151,7 +170,7 @@ def _ffn_graph(name: str, cfg: ArchConfig, seq_block: int) -> ModelGraph:
     so the schedulable VMEM working set is the padded one."""
     eb = _elem_bytes(cfg)
     seq_block = max(seq_block, LANE)
-    d, f = cfg.d_model, max(cfg.d_ff, cfg.d_model)
+    d, f = cfg.d_model, _ffn_width(cfg)
     up = LayerSpec(
         "ffn.up", LayerKind.GEMM,
         (GemmDims(M=seq_block, N=f, K=d, reps=2, b_reused=False),),  # gate+up
@@ -535,6 +554,7 @@ class MultiTenantServer:
         # tenants hit one compile cache (the pipelined path compiles
         # through _fused_epoch_fn instead)
         self._step_fns: Dict[str, Any] = {}
+        self._cfgs: Dict[str, ArchConfig] = {}
         self._groups: Dict[str, List[Tenant]] = {}
         self._batched: Dict[str, Any] = {}   # arch -> stacked params
         # un-jitted epoch / prefill cores per arch, composed into the
@@ -720,14 +740,19 @@ class MultiTenantServer:
         arriving mid-run).  Prompt tenants get deterministic prompt
         tokens, a prefill-block TenantTask for chunk scheduling, and a
         KV-working-set page reservation held until departure."""
-        aid = spec.model if isinstance(spec.model, str) else spec.model.name
+        cfg = resolve_arch(spec.model)
+        aid = spec.model if isinstance(spec.model, str) else cfg.name
+        # programs, buckets and prefix entries are keyed by cfg.name:
+        # one name must mean one config on this server
+        known = self._cfgs.setdefault(cfg.name, cfg)
+        if known != cfg:
+            raise ValueError(f"two configs named {cfg.name!r}")
         # a spec-pinned seed overrides the admission counter: the fleet
         # router stamps the GLOBAL admission index so replaying one
         # replica's scenario single-device rebuilds the exact same
         # params/prompt/tid (the bit-identical contract)
         i = spec.seed if spec.seed is not None else self._n_admitted
         self._n_admitted += 1
-        cfg = get_arch(aid).reduced()
         # a spec-pinned param_seed decouples MODEL identity from tenant
         # identity: every session on one system prompt shares a params
         # instance — the precondition for cross-tenant KV dedup
@@ -1008,9 +1033,7 @@ class MultiTenantServer:
         * deadline blown -> ``shed`` if this is (one of) the
           lowest-QoS arrivals waiting, else force-admit degraded — a
           strict-SLO tenant is never starved behind best-effort ones."""
-        aid = (spec.model if isinstance(spec.model, str)
-               else spec.model.name)
-        cfg = get_arch(aid).reduced()
+        cfg = resolve_arch(spec.model)
         if spec.prompt_len <= 0:
             return "admit"
         floor_kv = ("native" if cfg.family in ("ssm", "encdec")
@@ -1357,15 +1380,16 @@ class MultiTenantServer:
         working set: on the VMEM substrate a block grant must admit the
         block_fused_ffn claim, or the lowering would silently demote
         every granted LBM selection back to tiled LWM kernels.  Quoted
-        for the REAL cfg.d_ff — the dimension the kernel executes with
-        (block_fused_ffn asserts d_ff % block_f == 0).
+        for the REAL d_ff (:func:`_ffn_width`) — the dimension the
+        kernel executes with (block_fused_ffn asserts d_ff % block_f ==
+        0).
 
         Copy-on-write: the TenantModel's mapping may be the process-wide
         memoized instance shared with other tenants/servers, so the
         aligned MCTs go into a fresh ModelMapping instead of mutating
         the shared one."""
         eb = _elem_bytes(cfg)
-        need = fused_ffn_pages(seq_block, cfg.d_model, cfg.d_ff, eb)
+        need = fused_ffn_pages(seq_block, cfg.d_model, _ffn_width(cfg), eb)
         mcts = []
         for mct in tm.mapping.mcts:
             if mct.lbm is not None and mct.lbm.p_need < need:
@@ -1422,7 +1446,7 @@ class MultiTenantServer:
         """Lower the block's granted selections into the KernelPlan the
         decode step (or prefill chunk) executes.  An LBM grant covers
         the whole block; LWM layers each lower their own GEMM tile from
-        their own grant.  Lowered with the REAL cfg.d_ff — the dimension
+        their own grant.  Lowered with the REAL d_ff — the dimension
         the kernels execute with — not the padded scheduling-graph one."""
         cfg = t.cfg
         lbm = [(s, p) for s, p in sched if s.candidate.kind == "LBM"]
@@ -1431,7 +1455,7 @@ class MultiTenantServer:
                                        else None)
         return lower_selection(
             sel, pages, seq_block=seq_block or max(self.batch, LANE),
-            d_model=cfg.d_model, d_ff=cfg.d_ff,
+            d_model=cfg.d_model, d_ff=_ffn_width(cfg),
             dtype_bytes=_elem_bytes(cfg), head_dim=cfg.hd,
             ssm_chunk=cfg.ssm_chunk, down_pages=down_pages,
             kv_dtype=t.kv_dtype)
@@ -1485,8 +1509,7 @@ class MultiTenantServer:
         plan = self._lower_plan(t, sched, seq_block=self.prefill_block)
         t.plans.append(plan)
         chunk = lower_prefill_chunk(
-            plan, d_model=t.cfg.d_model,
-            d_ff=max(t.cfg.d_ff, t.cfg.d_model),
+            plan, d_model=t.cfg.d_model, d_ff=_ffn_width(t.cfg),
             dtype_bytes=_elem_bytes(t.cfg),
             align=self._chunk_align(t.cfg), max_tokens=self.prefill_block,
             remaining=t.prompt_len - t.pf_pos)
@@ -1798,10 +1821,8 @@ class MultiTenantServer:
             if step > horizon:
                 break
             if spec.prompt_len > 0:
-                aid = (spec.model if isinstance(spec.model, str)
-                       else spec.model.name)
                 kv = self.kv_dtype if self.kv_dtype != "auto" else "native"
-                delta -= _kv_reserve_pages(get_arch(aid).reduced(),
+                delta -= _kv_reserve_pages(resolve_arch(spec.model),
                                            self.batch, spec.prompt_len, kv)
         for t in self.tenants:
             if t.departed:
@@ -2679,8 +2700,7 @@ class FleetServer:
         if not (self.prefix_dedup and spec.param_seed is not None
                 and spec.prompt_seed is not None and spec.prompt_len > 0):
             return none
-        aid = spec.model if isinstance(spec.model, str) else spec.model.name
-        cfg = get_arch(aid).reduced()
+        cfg = resolve_arch(spec.model)
         if cfg.family == "encdec":
             return none
         # session prompts are admission-index-independent (prefix_seed /
@@ -2958,8 +2978,8 @@ def main() -> None:
                     help="KV cache storage precision (auto: downgrade "
                          "per admission when the pool is tight)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="fleet mode: split the host into N XLA devices "
-                         "and serve over an (N, 1) replica mesh")
+                    help="fleet mode: serve over an (N, 1) replica mesh "
+                         "of N chips (on the CPU backend, N host devices)")
     ap.add_argument("--oracle-sched", action="store_true",
                     help="force the per-tenant Algorithm 1 oracle "
                          "(disable the batched epoch planner)")
@@ -2970,6 +2990,8 @@ def main() -> None:
                     help="AOT-precompile predicted fused epoch programs "
                          "on a background thread")
     args = ap.parse_args()
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     arrivals = None
     if args.arrivals > 0:
         arrivals = PoissonArrivals(

@@ -125,6 +125,20 @@ class ArchConfig:
         ffn_active = self.experts_per_token * 3 * d * self.d_ff + d * self.num_experts
         return emb + L * (attn + ffn_active) + emb
 
+    def depth_cut(self, num_layers: int) -> "ArchConfig":
+        """Published widths, depth cut to ``num_layers`` whole layer
+        groups.  The name records the cut (``yi-9b@8L``), so programs
+        and caches keyed by name tell it apart from the uncut config
+        and from :meth:`reduced`."""
+        if num_layers == self.num_layers:
+            return self
+        period = self.attn_every or 1
+        if not 0 < num_layers < self.num_layers or num_layers % period:
+            raise ValueError(f"{self.name}: cannot cut {self.num_layers} "
+                             f"layers to {num_layers}")
+        return dataclasses.replace(self, name=f"{self.name}@{num_layers}L",
+                                   num_layers=num_layers)
+
     def reduced(self) -> "ArchConfig":
         """CPU smoke-test variant: same family/topology, tiny sizes."""
         return dataclasses.replace(

@@ -264,7 +264,7 @@ def opt_specs_shapes(params_shapes):
     return jax.eval_shape(adamw.init, params_shapes)
 
 
-# Keyed on (arch name, batch, max_len, kv_dtype, group): the AOT
+# Keyed on (arch config, batch, max_len, kv_dtype, group): the AOT
 # precompiler asks for the same handful of spec tuples on every warmup
 # round, and each construction costs two eval_shape traces.  Specs are
 # immutable ShapeDtypeStruct trees, so sharing is safe.
@@ -279,7 +279,7 @@ def decode_epoch_input_specs(cfg: ArchConfig, batch: int, max_len: int,
     AOT precompiler lowers fused epoch programs against.  ``group`` adds
     the leading tenant axis of a plan-bucketed item
     (:func:`make_decode_epoch_batched`)."""
-    ck = (cfg.name, batch, max_len, kv_dtype, group)
+    ck = (cfg, batch, max_len, kv_dtype, group)
     hit = _EPOCH_SPEC_CACHE.get(ck)
     if hit is not None:
         return hit

@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import random
-from typing import Dict, List, Optional, Tuple, Union
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple, Union
 
 from repro.core.allocator import DynamicCacheAllocator
 from repro.core.cache import CacheConfig, SharedCache
@@ -38,6 +38,9 @@ from repro.sim.engine import CorePool, DramResource, Engine
 from repro.sim.schedulers import (SCHEDULERS, BandwidthPolicy, CorePolicy,
                                   SchedulerSpec, TransparentParams,
                                   make_policy)
+
+if TYPE_CHECKING:
+    from repro.models.base import ArchConfig
 
 
 @dataclasses.dataclass
@@ -56,12 +59,13 @@ class TenantSpec:
 
     ``model`` is the tenant's layer graph in the analytic simulator; the
     real serving path (:class:`repro.launch.serve.MultiTenantServer`)
-    accepts the same spec with an *arch id string* instead, plus a
+    accepts the same spec with an *arch id string* (its reduced CPU
+    variant) or an ``ArchConfig`` (served as given) instead, plus a
     ``prompt_len``: the tenant then arrives mid-run with a real prompt
     that is prefilled (chunked, cache-aware) before it decodes
     ``n_inferences`` tokens and departs — one arrival vocabulary shared
     by the simulator and the server."""
-    model: Union[ModelGraph, str]
+    model: Union[ModelGraph, str, "ArchConfig"]
     arrive_at: float = 0.0           # seconds into the run
     n_inferences: Optional[int] = None   # depart after this many (None = horizon)
     qos_ms: Optional[float] = None   # per-tenant latency target override

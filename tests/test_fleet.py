@@ -57,6 +57,27 @@ def test_env_describe_reports_count():
     assert d.startswith("host_devices=") and "tcmalloc=" in d
 
 
+def test_compile_cache_placed_from_environment(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache: the call sets
+    no directory of its own."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert env.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = env.enable_compile_cache()
+        assert path == str(Path(__file__).resolve().parents[1]
+                           / ".jax_compile_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_fleet_scenario_shape():
     sc = FleetScenario(2, [[], []])
     assert sc.routes == [] and len(sc.per_replica) == 2
@@ -67,7 +88,8 @@ def test_relaunch_with_forced_devices():
     so the @needs4 tests execute instead of skipping everywhere."""
     if jax.device_count() >= 4:
         pytest.skip("already multi-device; @needs4 tests ran in-process")
-    env_ = dict(os.environ)
+    # the child forces CPU devices and must never reach for a chip
+    env_ = dict(os.environ, JAX_PLATFORMS="cpu")
     env_["XLA_FLAGS"] = env.merge_xla_flag(
         env_.get("XLA_FLAGS", ""),
         "--xla_force_host_platform_device_count", 4)

@@ -48,7 +48,7 @@ def test_train_loop_improves_and_resumes(tmp_path):
 def test_dryrun_subprocess_multidevice():
     """The real multi-pod dry-run entry point compiles a small cell on
     512 virtual devices in a fresh process."""
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "whisper-tiny", "--shape", "decode_32k", "--multi-pod"],
@@ -59,7 +59,7 @@ def test_dryrun_subprocess_multidevice():
 
 def test_serve_example_script():
     """examples/multi_tenant_serve.py runs as a script."""
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "examples/quickstart.py"],
         env=env, cwd=ROOT, capture_output=True, text=True, timeout=300)

@@ -96,6 +96,53 @@ def test_server_downgrades_under_pressure():
                int(k.split(":")[1][:-1]) <= 4 for k in kinds)
 
 
+def test_archconfig_spec_is_served_as_given():
+    """An arch-id string resolves to the reduced CPU variant; an
+    ArchConfig (here a depth cut) is served exactly as given, under its
+    own name; two different configs may not share one name."""
+    import dataclasses
+    from repro.launch.serve import MultiTenantServer
+    from repro.models.base import get_arch
+    from repro.sim.driver import TenantSpec
+    cut = get_arch("yi-9b").reduced().depth_cut(1)
+    assert cut.name == "yi-9b-smoke@1L" and cut.num_layers == 1
+    srv = MultiTenantServer(["yi-9b"], batch=1, max_len=16, total_pages=24,
+                            tenants=[TenantSpec(cut)], epoch_len=2)
+    out = srv.run(steps=2)
+    by_tid = {t.tid: t for t in srv.tenants}
+    assert by_tid["t0:yi-9b"].cfg == get_arch("yi-9b").reduced()
+    assert by_tid["t1:yi-9b-smoke@1L"].cfg is cut
+    assert all(info["tokens"] == 2 for info in out["tenants"].values())
+    clash = dataclasses.replace(cut, d_ff=384)
+    with pytest.raises(ValueError, match="two configs"):
+        srv.admit_routed(TenantSpec(clash))
+
+
+def test_arch_without_dense_ffn_plans_and_serves():
+    """Published mamba2 has d_ff 0: its FFN-block schedule and plans use
+    a d_model-wide block instead of dividing by a zero d_ff."""
+    import dataclasses
+    from repro.launch.serve import MultiTenantServer
+    from repro.models.base import get_arch
+    from repro.sim.driver import TenantSpec
+    cfg = dataclasses.replace(get_arch("mamba2-370m").reduced(), d_ff=0,
+                              name="mamba2-no-ffn")
+    srv = MultiTenantServer([], batch=1, max_len=256, total_pages=64,
+                            tenants=[TenantSpec(cfg, prompt_len=128,
+                                                n_inferences=4)])
+    out = srv.run(steps=4)
+    info = out["tenants"]["t0:mamba2-no-ffn"]
+    assert info["tokens"] == 1 + 4 and info["plans"]
+
+
+def test_peak_rates_keyed_by_device_kind():
+    from repro.launch.mesh import peaks
+    v5e = peaks("TPU v5 lite")
+    assert v5e["bf16_flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks("cpu")
+
+
 # ---------------------------------------------------------- mesh ------
 def test_host_mesh_axes():
     m = make_host_mesh()

@@ -256,7 +256,8 @@ def test_relaunch_fleet_routing_with_forced_devices():
     if jax.device_count() >= 2:
         pytest.skip("already multi-device; the routing test ran in-process")
     from repro.launch import env
-    env_ = dict(os.environ)
+    # the child forces CPU devices and must never reach for a chip
+    env_ = dict(os.environ, JAX_PLATFORMS="cpu")
     env_["XLA_FLAGS"] = env.merge_xla_flag(
         env_.get("XLA_FLAGS", ""),
         "--xla_force_host_platform_device_count", 2)

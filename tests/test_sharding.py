@@ -48,7 +48,8 @@ def test_relaunch_with_forced_devices():
     so the @needs4 tests execute instead of skipping everywhere."""
     if jax.device_count() >= 4:
         pytest.skip("already multi-device; @needs4 tests ran in-process")
-    env_ = dict(os.environ)
+    # the child forces CPU devices and must never reach for a chip
+    env_ = dict(os.environ, JAX_PLATFORMS="cpu")
     env_["XLA_FLAGS"] = env.merge_xla_flag(
         env_.get("XLA_FLAGS", ""),
         "--xla_force_host_platform_device_count", 4)
