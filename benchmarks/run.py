@@ -817,11 +817,11 @@ def serve_host_bench() -> dict:
             m["tps"].append(out["tokens_per_s"])
             m["ttft"].append(out["p95_ttft_s"])
             m["sched"].append(h["sched_wall_s"])
-            m["device"].append(h["device_wall_s"])
+            m["device"].append(h["dispatch_wall_s"])
             m["compiles"] += sum(h["epoch_compiles"])
             m["overlap"] &= all(s < d for s, d in
                                 zip(h["epoch_sched_walls"],
-                                    h["epoch_device_walls"]))
+                                    h["epoch_dispatch_walls"]))
             m["host"] = h
     entry = {
         "workload": {"residents": residents, "arrivals": 2,

@@ -97,7 +97,6 @@ import os
 import shutil
 import tempfile
 import threading
-import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple, Union
 
@@ -126,6 +125,8 @@ from repro.core.vmem import (LANE, PAGE_BYTES, VMEM_PAGES, fused_ffn_pages,
                              kv_row_bytes, lower_selection)
 from repro.distributed import sharding as shard
 from repro.distributed.fault_tolerance import StragglerPolicy
+from repro.launch.spans import (ADMIT, DISPATCH, FINISH, FIRST_TOKEN, PLAN,
+                                SpanRecorder, now as clock)
 from repro.models import model as M
 from repro.models.base import ArchConfig, get_arch
 from repro.models.ssm import CONV_K
@@ -412,8 +413,8 @@ class Tenant:
     # most-specific match over the server's qos_targets patterns —
     # _slack must not re-run the pattern match every epoch
     qos_target: Optional[float] = None
-    admitted_wall: Optional[float] = None
-    ttft: Optional[float] = None          # seconds admission -> 1st token
+    admitted_wall: Optional[float] = None  # due stamp: TTFT clock start
+    ttft: Optional[float] = None          # seconds due -> 1st token
     run_steps: int = 0                    # decode steps this run() call
     # ---- KV reservation accounting (best-effort degradation) --------
     kv_wanted: int = 0                    # pages the working set asks for
@@ -574,15 +575,10 @@ class MultiTenantServer:
         # (arch, kv_dtype) -> prefix cache seeder
         self._seed_jits: Dict[Tuple[str, str], Any] = {}
         # ---- host-path instrumentation ------------------------------
-        # per-epoch host scheduling wall vs dispatch wall (donation
-        # backpressure makes the dispatch wall track device time in
-        # steady state), plus per-epoch compile misses — the numbers the
-        # --host benchmark gates on
-        self._sched_walls: List[float] = []
-        self._device_walls: List[float] = []
-        self._admit_walls: List[float] = []
-        self._admit_wall = 0.0
-        self._epoch_compiles: List[int] = []
+        # spans (admit, plan, dispatch, first token, finish), per-task
+        # stamps and per-epoch counters of the current run, on the one
+        # clock every stamp of the server reads
+        self.spans = SpanRecorder()
         self._lookahead_adjusted = 0
         self._batched_runs = 0
         self._oracle_runs = 0
@@ -737,9 +733,8 @@ class MultiTenantServer:
     def _admit_spec(self, spec: TenantSpec,
                     due_wall: Optional[float] = None) -> Tenant:
         """Create a tenant from a spec (resident at construction or
-        arriving mid-run).  Prompt tenants get deterministic prompt
-        tokens, a prefill-block TenantTask for chunk scheduling, and a
-        KV-working-set page reservation held until departure."""
+        arriving mid-run), as one ``serve.admit`` span.  Its TTFT clock
+        starts at ``due_wall``, else at the admission's start."""
         cfg = resolve_arch(spec.model)
         aid = spec.model if isinstance(spec.model, str) else cfg.name
         # programs, buckets and prefix entries are keyed by cfg.name:
@@ -753,6 +748,22 @@ class MultiTenantServer:
         # params/prompt/tid (the bit-identical contract)
         i = spec.seed if spec.seed is not None else self._n_admitted
         self._n_admitted += 1
+        tid = f"t{i}:{aid}"
+        due = due_wall if due_wall is not None else clock()
+        stamps = self.spans.task(tid, due)
+        with self.spans.span(ADMIT, tid) as sp:
+            t = self._new_tenant(spec, cfg, aid, i, tid, due)
+        stamps["admit_start"], stamps["admit_end"] = sp.start, sp.end
+        if stamps["first_token"] is not None:
+            # full prefix hit: its first token came with the admission
+            stamps["first_dispatch"] = sp.end
+        return t
+
+    def _new_tenant(self, spec: TenantSpec, cfg: ArchConfig, aid: str,
+                    i: int, tid: str, due: float) -> Tenant:
+        """Prompt tenants get deterministic prompt tokens, a
+        prefill-block TenantTask for chunk scheduling, and a
+        KV-working-set page reservation held until departure."""
         # a spec-pinned param_seed decouples MODEL identity from tenant
         # identity: every session on one system prompt shares a params
         # instance — the precondition for cross-tenant KV dedup
@@ -765,7 +776,6 @@ class MultiTenantServer:
             self._step_fns[cfg.name] = jax.jit(
                 M.make_decode_step(cfg),
                 static_argnames=("plan", "kv_len"))
-        tid = f"t{i}:{aid}"
         tm = TenantModel(_ffn_graph(aid, cfg, seq_block=self.batch),
                          self.mapper)
         self._align_lbm_to_vmem(tm, cfg, max(self.batch, LANE))
@@ -846,7 +856,7 @@ class MultiTenantServer:
             t.caches = self._put_caches(
                 init_caches(params, cfg, self.batch, self.max_len,
                             kv_dtype=t.kv_dtype))
-        t.admitted_wall = due_wall if due_wall is not None else time.time()
+        t.admitted_wall = due
         self.tenants.append(t)
         self._unstack_bucket(cfg.name)
         self._groups.setdefault(cfg.name, []).append(t)
@@ -966,7 +976,7 @@ class MultiTenantServer:
           tenant has drained its decode work — and then prefilled
           whole-prompt, FCFS.  The queue wait counts against TTFT.
         """
-        now = time.time()
+        now = clock()
         for item in self._queue:
             if item[1] is None and self._due(item):
                 item[1] = now
@@ -1001,11 +1011,7 @@ class MultiTenantServer:
                     continue
             spec, due_wall, _ = self._queue.pop(0)
             self._defer_attempts.pop(id(spec), None)
-            # admission materializes params/caches — onboarding cost, not
-            # per-epoch scheduling; timed apart so sched_wall stays honest
-            a0 = time.perf_counter()
             self._admit_spec(spec, due_wall)
-            self._admit_wall += time.perf_counter() - a0
 
     def _malformed(self, spec: TenantSpec) -> Optional[str]:
         """Reject-reason for a spec the server cannot possibly serve
@@ -1082,7 +1088,7 @@ class MultiTenantServer:
             "model": aid, "state": STATE_SHED, "reason": reason,
             "step": self._clock, "qos_ms": spec.qos_ms,
             "prompt_len": spec.prompt_len,
-            "waited_s": (time.time() - due_wall
+            "waited_s": (clock() - due_wall
                          if due_wall is not None else None)})
         self.fault_log.record(self._clock, "shed", model=aid, reason=reason)
 
@@ -1203,7 +1209,7 @@ class MultiTenantServer:
         t.token = None
         t.state = STATE_PREEMPTED
         t.preemptions += 1
-        t.preempted_wall = time.time()
+        t.preempted_wall = clock()
         t.resume_step = (self._clock
                          + max(1, resume_after_epochs) * self.epoch_len)
         self.fault_log.record(self._clock, "preempt", tid=t.tid,
@@ -1262,7 +1268,7 @@ class MultiTenantServer:
         t.task.resume()
         t.state = STATE_RESUMED
         if t.preempted_wall is not None:
-            t.recovery_s.append(time.time() - t.preempted_wall)
+            t.recovery_s.append(clock() - t.preempted_wall)
             t.preempted_wall = None
         self.fault_log.record(self._clock, "resume", tid=t.tid,
                               kv_reserved=t.kv_reserved)
@@ -1349,7 +1355,7 @@ class MultiTenantServer:
         if self._straggler_left > 0:
             self._straggler_left -= 1
             dt = self._straggler_factor
-        if self.straggler.observe(len(self._device_walls), dt):
+        if self.straggler.observe(self.spans.epoch, dt):
             self.straggler.strikes = 0
             v = self._select_victim()
             self.fault_log.record(self._clock, "straggler_trip",
@@ -1574,8 +1580,10 @@ class MultiTenantServer:
             prev_pages = max(prev_pages, budget)
 
     def _stamp_ttft(self, t: Tenant, token: Any) -> None:
-        jax.block_until_ready(token)
-        t.ttft = time.time() - t.admitted_wall
+        with self.spans.span(FIRST_TOKEN, t.tid) as sp:
+            jax.block_until_ready(token)
+        t.ttft = sp.end - t.admitted_wall
+        self.spans.stamp(t.tid, "first_token", sp.end)
         self._record_page_scales(t)
 
     def _record_page_scales(self, t: Tenant) -> None:
@@ -1620,6 +1628,7 @@ class MultiTenantServer:
         t.chunks.append(t.prompt_len)
         kv = self._kv_len(t.prompt_len)
         fn = self._prefill_fn(t.cfg.name)
+        self.spans.stamp(t.tid, "first_dispatch", clock())
         with self._on_replica():
             tok, t.caches = fn(t.params, t.caches,
                                jnp.asarray(t.prompt), jnp.int32(0), t.enc,
@@ -1876,16 +1885,10 @@ class MultiTenantServer:
                 self._lookahead_adjusted += 1
 
     def _plan_epoch(self, now: float, steps: int) -> List[Tuple]:
-        """Timed wrapper around the epoch planner: the host `sched_wall`
-        half of the host/device overlap instrumentation."""
-        t0 = time.perf_counter()
-        a0 = self._admit_wall
-        try:
+        """The epoch planner, recorded as ``serve.plan`` segments around
+        the admissions inside it (the host `sched_wall`)."""
+        with self.spans.plan():
             return self._plan_epoch_inner(now, steps)
-        finally:
-            adm = self._admit_wall - a0
-            self._sched_walls.append(time.perf_counter() - t0 - adm)
-            self._admit_walls.append(adm)
 
     def _plan_epoch_inner(self, now: float, steps: int) -> List[Tuple]:
         """Host-side scheduling for one epoch: admit due arrivals,
@@ -2258,6 +2261,8 @@ class MultiTenantServer:
         _, t, _, chunk = item
         kv = self._kv_len(t.pf_pos + chunk)
         fn = self._prefill_fn(t.cfg.name)
+        if not t.pf_computed:
+            self.spans.stamp(t.tid, "first_dispatch", clock())
         with self._on_replica():
             tok, t.caches = fn(
                 t.params, t.caches,
@@ -2271,28 +2276,38 @@ class MultiTenantServer:
         return None
 
     def _dispatch_epoch(self, work: List[Tuple]) -> None:
-        """Timed wrapper around the epoch dispatcher: the `device_wall`
-        half of the host/device overlap instrumentation (donation
-        backpressure makes the dispatch wall track device time in steady
-        state), plus the per-epoch compile-miss delta — new fused or
-        prefill programs built while dispatching this epoch."""
-        m0 = self._fused_jits.misses + self._prefill_jits.misses
-        t0 = time.perf_counter()
-        try:
-            self._dispatch_epoch_inner(work)
-        finally:
-            self._device_walls.append(time.perf_counter() - t0)
-            self._epoch_compiles.append(
-                self._fused_jits.misses + self._prefill_jits.misses - m0)
-            self._observe_epoch()
+        """Dispatch one epoch as a ``serve.dispatch`` span (the dispatch
+        wall: donation backpressure makes it track device time in steady
+        state), then stamp the first token of every prompt whose final
+        chunk it dispatched — the blocking reads happen only after every
+        one of the epoch's device calls is in flight — and log the
+        epoch's counters, with the programs (fused or prefill) built
+        while dispatching it."""
+        m0 = self.compile_misses()
+        with self.spans.span(DISPATCH):
+            finished = self._dispatch_epoch_inner(work)
+        built = self.compile_misses() - m0
+        for t, tok in finished:
+            self._stamp_ttft(t, tok)
+        rows_k = [(self.batch * (len(it[1]) if it[0] == "bucket" else 1),
+                   it[3]) for it in work if it[0] != "prefill"]
+        chunks = [it[3] for it in work if it[0] == "prefill"]
+        self.spans.log_epoch(
+            decode_items=len(rows_k),
+            decode_row_steps=sum(r * k for r, k in rows_k),
+            decode_steps=max((k for _, k in rows_k), default=0),
+            prefill_chunks=len(chunks),
+            prefill_tokens=self.batch * sum(chunks),
+            programs_built=built)
+        self._observe_epoch()
 
-    def _dispatch_epoch_inner(self, work: List[Tuple]) -> None:
+    def _dispatch_epoch_inner(self, work: List[Tuple]) -> List[Tuple]:
         """Launch one epoch's work: the prefill chunks dispatch first
         (each through its cached per-arch chunk program), then ALL the
         decode items as ONE fused device call.  Everything is
         dispatched asynchronously and nothing here blocks on a device
-        value — tokens and caches stay on device (the only sync is the
-        TTFT stamp when a tenant's final prefill chunk lands)."""
+        value — tokens and caches stay on device.  Returns (tenant,
+        token) for every prompt whose final chunk was dispatched."""
         decode_items, finished = [], []
         for item in work:
             if item[0] == "prefill":
@@ -2302,9 +2317,7 @@ class MultiTenantServer:
             else:
                 decode_items.append(item)
         if not decode_items:
-            for t, tok in finished:
-                self._stamp_ttft(t, tok)
-            return
+            return finished
         fn = self._fused_epoch_fn(decode_items)
         params_list, caches_list, token_list, index_list, enc_list = (
             [], [], [], [], [])
@@ -2350,10 +2363,7 @@ class MultiTenantServer:
                 t.token = toks[:, -1:]
                 t.outputs.append(toks)
                 self._advance(t, k)
-        # TTFT stamps last: the blocking reads happen only after every
-        # one of this epoch's device calls is in flight
-        for t, tok in finished:
-            self._stamp_ttft(t, tok)
+        return finished
 
     def _serve_one_step(self, t: Tenant, now: float) -> None:
         """Serial reference: schedule, charge, lower, and dispatch ONE
@@ -2410,23 +2420,20 @@ class MultiTenantServer:
     def _begin_run(self, steps: int) -> None:
         """Per-run reset (start of :meth:`run`; the fleet driver calls
         it once per replica before interleaving their epochs)."""
-        self._run_t0 = time.time()
-        self._run_steps = steps
         # host-path instrumentation is per-run: a warmed-server replay
         # reports its own epochs (the post-warmup compile gate)
-        self._sched_walls = []
-        self._device_walls = []
-        self._admit_walls = []
-        self._admit_wall = 0.0
-        self._epoch_compiles = []
+        self.spans.reset()
+        self._run_t0 = self.spans.t0
+        self._run_steps = steps
         self._lookahead_adjusted = 0
         self._batched_runs = 0
         self._oracle_runs = 0
         for t in self.tenants:
             t.run_steps = 0
-            if t.admitted_wall is None or not t.outputs:
+            if not t.outputs:
                 # TTFT clock starts with the run
                 t.admitted_wall = self._run_t0
+                self.spans.task(t.tid, self._run_t0)
         self._run_tokens_before = sum(t.tokens_served for t in self.tenants)
 
     def run(self, steps: int = 16) -> Dict[str, Any]:
@@ -2439,10 +2446,10 @@ class MultiTenantServer:
                 self._dispatch_epoch(pending)
                 # one-epoch-ahead: this epoch is still executing on
                 # device (async dispatch); schedule the next one now
-                pending = self._plan_epoch(time.time() - t0, steps)
+                pending = self._plan_epoch(clock() - t0, steps)
         else:
             while True:
-                now = time.time() - t0   # once per round, not per tenant
+                now = clock() - t0   # once per round, not per tenant
                 self._apply_due_faults(steps)
                 self._try_resume()
                 self._admit_due(steps)
@@ -2467,14 +2474,22 @@ class MultiTenantServer:
 
     def _finish_run(self) -> Dict[str, Any]:
         """Close out a run: hand bucketed caches back to their tenants,
-        then fetch device values exactly once, after the last epoch."""
+        then fetch device values exactly once, after the last epoch (the
+        ``serve.finish`` span)."""
         t0 = self._run_t0
-        for name in list(self._bucket_caches):
-            self._unstack_bucket(name)
-        live = [t.token for t in self.tenants if t.token is not None]
-        if live:
-            jax.block_until_ready(live)
-        wall = time.time() - t0
+        with self.spans.span(FINISH):
+            for name in list(self._bucket_caches):
+                self._unstack_bucket(name)
+            live = [t.token for t in self.tenants if t.token is not None]
+            if live:
+                jax.block_until_ready(live)
+            wall = clock() - t0
+            # full decoded history [B, total_steps] per tenant, fetched
+            # here (the loop itself never pulled a value)
+            outputs = {t.tid: (np.concatenate(
+                [np.asarray(o) for o in t.outputs], axis=-1)
+                if t.outputs else np.zeros((self.batch, 0), np.int32))
+                for t in self.tenants}
         served = (sum(t.tokens_served for t in self.tenants)
                   - self._run_tokens_before)
         # p95 over THIS run's admissions only (a warmed server keeps
@@ -2502,12 +2517,7 @@ class MultiTenantServer:
                         "state": t.state,
                         "preemptions": t.preemptions,
                         "recovery_s": list(t.recovery_s),
-                        # full decoded history [B, total_steps], fetched
-                        # here (the loop itself never pulled a value)
-                        "output": (np.concatenate(
-                            [np.asarray(o) for o in t.outputs], axis=-1)
-                            if t.outputs else np.zeros((self.batch, 0),
-                                                       np.int32))}
+                        "output": outputs[t.tid]}
                 for t in self.tenants
             },
             "mode": "pipelined" if self.pipeline else "serial",
@@ -2544,24 +2554,31 @@ class MultiTenantServer:
 
     def _host_stats(self) -> Dict[str, Any]:
         """Host-off-the-critical-path instrumentation for the finished
-        run: per-epoch host scheduling wall vs dispatch wall, compile
-        misses per epoch, batched-vs-oracle planner mix, and the AOT /
-        jit-cache counters — everything the --host benchmark gates on."""
-        sched = float(sum(self._sched_walls))
-        device = float(sum(self._device_walls))
+        run, all read from the span recorder: per-epoch host scheduling
+        wall vs dispatch wall, compile misses per epoch, each span's
+        total, each task's stamps and each epoch's counters; then the
+        batched-vs-oracle planner mix and the AOT / jit-cache counters."""
+        rec = self.spans
+        totals = rec.totals()
+        sched = totals[PLAN]["seconds"]
+        dispatch = totals[DISPATCH]["seconds"]
         entries = [self._fused_jits.peek(k) for k in self._fused_jits.keys()]
         entries = [e for e in entries if isinstance(e, _CompiledEntry)]
         return {
-            "epochs": len(self._device_walls),
+            "epochs": totals[DISPATCH]["count"],
             "sched_wall_s": sched,
-            "device_wall_s": device,
+            "dispatch_wall_s": dispatch,
             # tenant onboarding (param/cache materialization, prompt
             # synthesis) — reported apart so sched_wall is scheduling only
-            "admit_wall_s": float(sum(self._admit_walls)),
-            "sched_frac": sched / device if device > 0 else 0.0,
-            "epoch_sched_walls": [round(x, 6) for x in self._sched_walls],
-            "epoch_device_walls": [round(x, 6) for x in self._device_walls],
-            "epoch_compiles": list(self._epoch_compiles),
+            "admit_wall_s": totals[ADMIT]["seconds"],
+            "sched_frac": sched / dispatch if dispatch > 0 else 0.0,
+            "epoch_sched_walls": [round(x, 6) for x in rec.per_epoch(PLAN)],
+            "epoch_dispatch_walls": [round(x, 6)
+                                     for x in rec.durations(DISPATCH)],
+            "epoch_compiles": [e["programs_built"] for e in rec.epoch_log],
+            "spans": totals,
+            "tasks": rec.task_stamps(),
+            "epoch_log": list(rec.epoch_log),
             "batched_runs": self._batched_runs,
             "oracle_runs": self._oracle_runs,
             "lookahead_adjusted": self._lookahead_adjusted,
@@ -2671,7 +2688,7 @@ class FleetServer:
         if arrivals is not None:
             specs += arrivals.specs()
         specs.sort(key=lambda s: s.arrive_at)
-        now = time.time()
+        now = clock()
         for spec in specs:
             if spec.arrive_at <= 0.0:
                 self._route(spec, now)
@@ -2740,7 +2757,7 @@ class FleetServer:
         return r
 
     def _route_due(self) -> None:
-        now = time.time()
+        now = clock()
         for item in self._queue:
             if item[1] is None and item[2] <= self._clock:
                 item[1] = now   # TTFT clock: the request exists from here
@@ -2769,7 +2786,7 @@ class FleetServer:
                                   target=f"r{r}", skipped="last live replica")
             return []
         self._dead.add(r)
-        kill_wall = time.time()
+        kill_wall = clock()
         srv = self.replicas[r]
         by_tid: Dict[str, TenantSpec] = {}
         for spec in self.scenario.per_replica[r]:
@@ -2837,7 +2854,7 @@ class FleetServer:
 
     # -------------------------------------------------------------- run --
     def run(self, steps: int = 16) -> Dict[str, Any]:
-        t0 = time.time()
+        t0 = clock()
         for srv in self.replicas:
             srv._begin_run(steps)
         self._apply_fleet_faults(steps)
@@ -2870,13 +2887,13 @@ class FleetServer:
             # are at an epoch boundary when their chip disappears
             self._apply_fleet_faults(steps)
             self._route_due()
-            now = time.time() - t0
+            now = clock() - t0
             pendings = [None if r in self._dead
                         else srv._plan_epoch(now, steps)
                         for r, srv in enumerate(self.replicas)]
             self._clock += self.epoch_len
         results = [srv._finish_run() for srv in self.replicas]
-        return self._merge(results, time.time() - t0)
+        return self._merge(results, clock() - t0)
 
     def _merge(self, results: List[Dict[str, Any]],
                wall: float) -> Dict[str, Any]:
@@ -3050,7 +3067,7 @@ def main() -> None:
     host = out.get("host") or {}
     if host.get("epochs"):
         print(f"[serve] host: sched {host['sched_wall_s'] * 1e3:.1f}ms vs "
-              f"device {host['device_wall_s'] * 1e3:.1f}ms "
+              f"dispatch {host['dispatch_wall_s'] * 1e3:.1f}ms "
               f"({host['sched_frac'] * 100:.1f}%), "
               f"{host['batched_runs']} batched / {host['oracle_runs']} "
               f"oracle runs, compiles/epoch {host['epoch_compiles']}, "

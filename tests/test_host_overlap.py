@@ -3,7 +3,7 @@
 The scheduling host must stay off the critical path once the epoch
 programs are warm: a warmed server replays an identical workload with
 zero new fused-jit compilations and a per-epoch ``sched_wall`` strictly
-under the per-epoch ``device_wall``.  The jit caches that make this
+under the per-epoch ``dispatch_wall``.  The jit caches that make this
 possible are LRU-bounded, so steady state must also show pure cache
 hits — no evictions, no misses.  QoS targets are pinned once at
 admission with longest-pattern matching, so a re-resolved pattern map
@@ -40,12 +40,12 @@ def test_warm_replay_sched_wall_under_device_wall(warmed_server):
     h = out["host"]
     # One trailing plan call may see no runnable tenants and dispatch
     # nothing; compare only the epochs that actually hit the device.
-    device = h["epoch_device_walls"]
-    sched = h["epoch_sched_walls"][:len(device)]
-    assert len(device) > 0
-    for i, (s, d) in enumerate(zip(sched, device)):
+    dispatch = h["epoch_dispatch_walls"]
+    sched = h["epoch_sched_walls"][:len(dispatch)]
+    assert len(dispatch) > 0
+    for i, (s, d) in enumerate(zip(sched, dispatch)):
         assert s < d, (f"epoch {i}: host planning ({s * 1e3:.2f}ms) is on "
-                       f"the critical path (device {d * 1e3:.2f}ms)")
+                       f"the critical path (dispatch {d * 1e3:.2f}ms)")
 
 
 # ---------------------------------------- satellite: bounded caches ---
