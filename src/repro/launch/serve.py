@@ -131,7 +131,8 @@ from repro.models import model as M
 from repro.models.base import ArchConfig, get_arch
 from repro.models.ssm import CONV_K
 from repro.models.transformer import (init_caches, num_groups,
-                                      seed_caches_from_prefix)
+                                      seed_caches_from_prefix,
+                                      ssm_state_in_place)
 from repro.sim.driver import (BackoffPolicy, FleetScenario, PoissonArrivals,
                               TenantSpec)
 from repro.sim.faults import FaultEvent, FaultLog, FaultPlan
@@ -2289,13 +2290,17 @@ class MultiTenantServer:
         built = self.compile_misses() - m0
         for t, tok in finished:
             self._stamp_ttft(t, tok)
+        # (rows, k, whether the SSM state runs the in-place kernel)
         rows_k = [(self.batch * (len(it[1]) if it[0] == "bucket" else 1),
-                   it[3]) for it in work if it[0] != "prefill"]
+                   it[3], ssm_state_in_place(
+                       (it[1][0] if it[0] == "bucket" else it[1]).cfg))
+                  for it in work if it[0] != "prefill"]
         chunks = [it[3] for it in work if it[0] == "prefill"]
         self.spans.log_epoch(
             decode_items=len(rows_k),
-            decode_row_steps=sum(r * k for r, k in rows_k),
-            decode_steps=max((k for _, k in rows_k), default=0),
+            decode_row_steps=sum(r * k for r, k, _ in rows_k),
+            ssm_inplace_row_steps=sum(r * k for r, k, ip in rows_k if ip),
+            decode_steps=max((k for _, k, _ in rows_k), default=0),
             prefill_chunks=len(chunks),
             prefill_tokens=self.batch * sum(chunks),
             programs_built=built)
@@ -2564,6 +2569,7 @@ class MultiTenantServer:
         dispatch = totals[DISPATCH]["seconds"]
         entries = [self._fused_jits.peek(k) for k in self._fused_jits.keys()]
         entries = [e for e in entries if isinstance(e, _CompiledEntry)]
+        row_steps = sum(e["decode_row_steps"] for e in rec.epoch_log)
         return {
             "epochs": totals[DISPATCH]["count"],
             "sched_wall_s": sched,
@@ -2579,6 +2585,11 @@ class MultiTenantServer:
             "spans": totals,
             "tasks": rec.task_stamps(),
             "epoch_log": list(rec.epoch_log),
+            # share of the decode row-steps whose SSM state the in-place
+            # kernel updated
+            "ssm_inplace_share": (sum(e["ssm_inplace_row_steps"]
+                                      for e in rec.epoch_log) / row_steps
+                                  if row_steps else 0.0),
             "batched_runs": self._batched_runs,
             "oracle_runs": self._oracle_runs,
             "lookahead_adjusted": self._lookahead_adjusted,

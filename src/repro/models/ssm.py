@@ -184,7 +184,8 @@ def mamba2_forward(params: Params, x: jnp.ndarray, cfg: ArchConfig,
 
 
 def ssd_decode_step(params: Params, x: jnp.ndarray, cfg: ArchConfig,
-                    state: Dict[str, jnp.ndarray]
+                    state: Dict[str, jnp.ndarray],
+                    layer: Optional[jnp.ndarray] = None
                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """O(1) recurrent step.  x: [b, 1, d]; state {conv, ssm}.
 
@@ -192,7 +193,13 @@ def ssd_decode_step(params: Params, x: jnp.ndarray, cfg: ArchConfig,
     serving path scans this step over a K-token epoch with the state as
     a donated carry, and a carry whose dtype drifts (e.g. an f32
     accumulation escaping into a bf16 conv window) would break both the
-    scan signature and in-place donation."""
+    scan signature and in-place donation.
+
+    With ``layer`` given, ``state["ssm"]`` is the whole stacked state
+    [L, b, h, n, p] of a deep stack and the returned ``"ssm"`` is that
+    stack with layer ``layer`` updated in place by the Pallas kernel
+    (kernels/ssd_decode.py): one read and one write of the layer's
+    state, the same float32 update and readout."""
     b, _, d = x.shape
     di, n, nh, p = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     zxbcdt = linear(params["in_proj"], x)
@@ -208,14 +215,20 @@ def ssd_decode_step(params: Params, x: jnp.ndarray, cfg: ArchConfig,
     xh = xs.reshape(b, nh, p).astype(jnp.float32)
     Bf = B[:, 0].astype(jnp.float32)                             # [b,n]
     Cf = C[:, 0].astype(jnp.float32)
-    h = state["ssm"] * dA[:, :, None, None] + jnp.einsum(
-        "bh,bn,bhp->bhnp", dt, Bf, xh)
-    y = jnp.einsum("bn,bhnp->bhp", Cf, h) + params["D"][None, :, None] * xh
+    if layer is None:
+        h = state["ssm"] * dA[:, :, None, None] + jnp.einsum(
+            "bh,bn,bhp->bhnp", dt, Bf, xh)
+        y = jnp.einsum("bn,bhnp->bhp", Cf, h)
+        h = h.astype(state["ssm"].dtype)
+    else:
+        from repro.kernels import ops as kops
+        y, h = kops.ssd_decode_inplace(state["ssm"], layer, dA, dt, xh, Bf,
+                                       Cf)
+    y = y + params["D"][None, :, None] * xh
     y = y.reshape(b, 1, di).astype(x.dtype)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
     return linear(params["out_proj"], y), {
-        "conv": new_conv.astype(state["conv"].dtype),
-        "ssm": h.astype(state["ssm"].dtype)}
+        "conv": new_conv.astype(state["conv"].dtype), "ssm": h}
 
 
 def init_ssm_state(cfg: ArchConfig, batch: int) -> Dict[str, jnp.ndarray]:

@@ -39,6 +39,16 @@ _REMAT_POLICY = "full"   # "full" | "dots" (save matmul outputs)
 _DECODE_UNROLL_MAX_GROUPS = 8
 
 
+def ssm_state_in_place(cfg: ArchConfig) -> bool:
+    """Whether :func:`decode_step` updates this model's SSM state in
+    place in its stack, through the Pallas decode kernel: a pure SSM
+    stack deep enough for the stacked-cache scan.  Shallow stacks
+    update per-group buffers in jnp; hybrid stacks keep their SSM
+    state inside each attention group."""
+    return (cfg.family == "ssm"
+            and num_groups(cfg) > _DECODE_UNROLL_MAX_GROUPS)
+
+
 def set_scan_unroll(u: int) -> None:
     global _SCAN_UNROLL
     _SCAN_UNROLL = max(1, int(u))
@@ -143,10 +153,11 @@ def _dense_block(p: Params, x, cfg: ArchConfig, *, causal=True, kv_cache=None,
 
 
 def _ssm_block(p: Params, x, cfg: ArchConfig, state=None, decode=False,
-               plan=None):
+               plan=None, layer=None):
     y = rms_norm(p["ln1"], x, cfg.norm_eps)
     if decode:
-        out, new_state = ssd_decode_step(p["mamba"], y, cfg, state)
+        out, new_state = ssd_decode_step(p["mamba"], y, cfg, state,
+                                         layer=layer)
     else:
         chunk = plan.ssm_chunk if plan is not None else None
         out, new_state = mamba2_forward(p["mamba"], y, cfg, state,
@@ -414,7 +425,10 @@ def decode_step(params: Params, token: jnp.ndarray, caches, index: jnp.ndarray,
     O(tokens written) plus the unavoidable attention read.  Deep stacks
     keep the compact scan-over-layers HLO — essential for the
     512-device dry-run — carrying the stacked caches through the scan
-    instead of the xs/ys re-stack."""
+    instead of the xs/ys re-stack.  A deep pure-SSM stack
+    (:func:`ssm_state_in_place`) hands its whole stacked state to the
+    Pallas decode kernel, which rewrites layer g's slice in place: no
+    slice of the state is taken or written back around it."""
     x = embed(params["embed"], token)
     positions = jnp.full((1, 1), index, jnp.int32)
     G = num_groups(cfg)
@@ -452,6 +466,22 @@ def decode_step(params: Params, token: jnp.ndarray, caches, index: jnp.ndarray,
             gp, xp = stk if cfg.family == "encdec" else (stk, None)
             x, new_caches[g] = run_group(x, gp, xp, new_caches[g])
         new_caches = tuple(new_caches)
+    elif ssm_state_in_place(cfg):
+        # the SSM stack goes whole to the kernel, which rewrites layer g
+        # in place; only the small conv window is sliced and written back
+        def group_fn(carry, scan_in):
+            x, caches = carry
+            gp, g = scan_in
+            conv = jax.lax.dynamic_index_in_dim(caches["conv"], g, 0,
+                                                keepdims=False)
+            x, new = _ssm_block(gp, x, cfg, decode=True, layer=g,
+                                state={"conv": conv, "ssm": caches["ssm"]})
+            return (x, {"conv": jax.lax.dynamic_update_index_in_dim(
+                caches["conv"], new["conv"], g, 0), "ssm": new["ssm"]}), None
+
+        (x, new_caches), _ = jax.lax.scan(
+            group_fn, (x, caches), (layer_stack, jnp.arange(G)),
+            unroll=_SCAN_UNROLL)
     else:
         def group_fn(carry, scan_in):
             x, caches = carry

@@ -6,12 +6,14 @@ Covers the PR acceptance contract:
     transformer, an MoE, and an SSM tenant, with and without a
     KernelPlan, including a plan switch at an epoch boundary,
   * a plan-bucketed batched decode (vmap over the tenant axis) is
-    bit-identical per tenant slice,
+    bit-identical per tenant slice (for the stacked SSM state of a deep
+    stack: the same tokens, the state at float32 precision),
   * the pipelined server loop reproduces the serial reference loop
     bit-for-bit (decoded outputs, choice traces, lbm_frac) with an
     unchanged NEC ``dram_total`` — epoch charging with ``repeat=K``
     equals charging every step individually,
-  * the epoch decode donates its caches (in-place KV/SSM update),
+  * the epoch decode donates its caches (in-place KV/SSM update), the
+    stacked SSM state of a deep stack included,
   * bounded-window attention (``kv_len``) matches the full-length read,
   * QoS slack is seeded at the target until a tenant has served,
   * the starvation fallback selects the minimum-footprint LWM.
@@ -29,10 +31,22 @@ from repro.core.nec import Traffic
 from repro.core.vmem import lower_selection
 from repro.models import model as M
 from repro.models.base import get_arch
-from repro.models.transformer import init_caches
+from repro.models.transformer import init_caches, ssm_state_in_place
 
 KEY = jax.random.PRNGKey(0)
+# the reduced mamba2 deepened past _DECODE_UNROLL_MAX_GROUPS, so its
+# decode runs the stacked-state scan and the in-place SSD kernel
+DEEP_SSM = "mamba2-370m@10L"
 EQUIV_ARCHS = ["yi-9b", "olmoe-1b-7b", "mamba2-370m"]
+
+
+def _cfg(arch: str):
+    if arch == DEEP_SSM:
+        cfg = dataclasses.replace(get_arch("mamba2-370m").reduced(),
+                                  num_layers=10, name="mamba2-370m-10L")
+        assert ssm_state_in_place(cfg)
+        return cfg
+    return get_arch(arch).reduced()
 
 
 def _cand(kind: str, p_need: int = 8) -> MappingCandidate:
@@ -67,12 +81,13 @@ def _trees_equal(a, b) -> bool:
 
 
 # ------------------------------------------------- scan == sequential --
-@pytest.mark.parametrize("arch", EQUIV_ARCHS)
+@pytest.mark.parametrize("arch", EQUIV_ARCHS + [DEEP_SSM])
 def test_epoch_scan_matches_sequential(arch):
     """One K-step on-device scan must reproduce K sequential decode
     steps bit-for-bit (tokens AND caches) for every model family the
-    serving loop hosts."""
-    cfg = get_arch(arch).reduced()
+    serving loop hosts, and for the stacked SSM state that the in-place
+    kernel updates."""
+    cfg = _cfg(arch)
     params = M.init_params(cfg, jax.random.PRNGKey(1))
     token = jnp.zeros((1, 1), jnp.int32)
     k = 4
@@ -108,14 +123,9 @@ def test_epoch_plan_switch_at_boundary_matches_sequential():
     assert _trees_equal(caches, want_caches)
 
 
-@pytest.mark.parametrize("arch", ["yi-9b", "olmoe-1b-7b"])
-def test_bucketed_batched_decode_matches_single(arch):
-    """Two same-arch tenants (different params) stacked into one
-    vmapped bucket decode must match their individual epochs
-    bit-for-bit."""
-    cfg = get_arch(arch).reduced()
-    k = 3
-    plan = _plan(cfg, "LBM", 4096)
+def _bucket_and_singles(cfg, plan, k=3):
+    """Two same-arch tenants (different params): their individual
+    epochs, and the vmapped bucket epoch of both."""
     tenants = []
     for i in range(2):
         p = M.init_params(cfg, jax.random.PRNGKey(10 + i))
@@ -130,16 +140,40 @@ def test_bucketed_batched_decode_matches_single(arch):
                   static_argnames=("plan", "k"))
     btoks, bcaches = bep(sp, sc, jnp.zeros((2, 1, 1), jnp.int32),
                          jnp.zeros((2,), jnp.int32), plan=plan, k=k)
+    return singles, btoks, bcaches
+
+
+@pytest.mark.parametrize("arch", ["yi-9b", "olmoe-1b-7b"])
+def test_bucketed_batched_decode_matches_single(arch):
+    """Two same-arch tenants (different params) stacked into one
+    vmapped bucket decode must match their individual epochs
+    bit-for-bit."""
+    cfg = get_arch(arch).reduced()
+    singles, btoks, bcaches = _bucket_and_singles(
+        cfg, _plan(cfg, "LBM", 4096))
     for i, (toks, caches) in enumerate(singles):
         np.testing.assert_array_equal(np.asarray(btoks[i]), np.asarray(toks))
         assert _trees_equal(
             jax.tree_util.tree_map(lambda x, i=i: x[i], bcaches), caches)
 
 
-def test_epoch_decode_donates_caches():
-    """The serving epoch entry point updates KV caches in place: the
-    donated input buffers must be consumed by the call."""
-    cfg = get_arch("yi-9b").reduced()
+def test_bucketed_stacked_ssm_matches_single():
+    """The in-place SSD kernel runs under the bucket's vmap (a grid axis
+    over tenants): each tenant slice decodes the same tokens as its own
+    epoch, and its state agrees at float32 precision.  Not bit for bit:
+    the vmapped projections take other XLA CPU dot kernels than the
+    unbatched ones (the same holds for the unrolled reduced mamba2)."""
+    singles, btoks, bcaches = _bucket_and_singles(_cfg(DEEP_SSM), None)
+    for i, (toks, caches) in enumerate(singles):
+        np.testing.assert_array_equal(np.asarray(btoks[i]), np.asarray(toks))
+        for got, want in zip(jax.tree_util.tree_leaves(bcaches),
+                             jax.tree_util.tree_leaves(caches)):
+            np.testing.assert_allclose(np.asarray(got[i], np.float32),
+                                       np.asarray(want, np.float32),
+                                       rtol=1e-5, atol=1e-5)
+
+
+def _assert_epoch_donates_caches(cfg):
     params = M.init_params(cfg, jax.random.PRNGKey(3))
     caches = init_caches(params, cfg, 1, 16)
     ep = jax.jit(M.make_decode_epoch(cfg), static_argnames=("plan", "k"),
@@ -149,6 +183,18 @@ def test_epoch_decode_donates_caches():
     jax.block_until_ready(toks)
     assert all(leaf.is_deleted()
                for leaf in jax.tree_util.tree_leaves(caches))
+
+
+def test_epoch_decode_donates_caches():
+    """The serving epoch entry point updates KV caches in place: the
+    donated input buffers must be consumed by the call."""
+    _assert_epoch_donates_caches(get_arch("yi-9b").reduced())
+
+
+def test_stacked_ssm_epoch_donates_caches():
+    """The same for the stacked SSM state and conv window of a deep
+    stack, whose state the kernel updates through an aliased buffer."""
+    _assert_epoch_donates_caches(_cfg(DEEP_SSM))
 
 
 def test_kv_len_window_matches_full_read():

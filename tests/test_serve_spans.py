@@ -8,6 +8,7 @@ trace carries the spans under their bare names, the epoch and prefill
 programs keep the names the benchmark's trace patterns match, and the
 benchmark's readers of the recorder read the run.
 """
+import dataclasses
 import glob
 import importlib.util
 import json
@@ -24,6 +25,7 @@ import pytest
 
 from repro.launch import spans as S
 from repro.launch.serve import MultiTenantServer
+from repro.models.base import get_arch
 from repro.sim.driver import TenantSpec
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -232,3 +234,25 @@ def test_plan_is_recorded_in_segments_around_admissions():
                      (S.PLAN, 0, 0), (S.DISPATCH, 0, 0), (S.PLAN, 1, 0)]
     assert len(rec.per_epoch(S.PLAN)) == 2
     assert rec.totals()[S.PLAN]["count"] == 4
+
+
+def test_ssm_inplace_row_steps_count_the_stacked_state_rows(served):
+    """Each epoch counts the decode row-steps whose SSM state went
+    through the in-place kernel: none for the shallow (unrolled) mamba2
+    and the yi tasks of the shared run; all of them for a mamba2 deep
+    enough for the stacked state."""
+    _, out = served
+    assert all(e["ssm_inplace_row_steps"] == 0
+               for e in out["host"]["epoch_log"])
+    assert out["host"]["ssm_inplace_share"] == 0.0
+    deep = dataclasses.replace(get_arch("mamba2-370m").reduced(),
+                               num_layers=10, name="mamba2-370m-10L")
+    srv = MultiTenantServer([], batch=1, max_len=128, epoch_len=4)
+    srv.enqueue([TenantSpec(deep, arrive_at=0.0, n_inferences=8,
+                            prompt_len=32)])
+    out = srv.run()
+    log = out["host"]["epoch_log"]
+    assert sum(e["decode_row_steps"] for e in log) == 8
+    assert all(e["ssm_inplace_row_steps"] == e["decode_row_steps"]
+               for e in log)
+    assert out["host"]["ssm_inplace_share"] == 1.0

@@ -9,6 +9,7 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from repro.core.vmem import (CHIP_VMEM_BYTES, DEFAULT_SCOPED_VMEM_BYTES,
                              fused_ffn_pages, fused_ffn_vmem_bytes,
                              lower_matmul_tile)
 from repro.kernels import ops
+from repro.kernels.ssd_decode import ssd_decode_update
 from repro.models.base import all_archs, get_arch
 
 YI = get_arch("yi-9b")
@@ -96,6 +98,65 @@ def test_largest_fused_plan_compiles_above_default_vmem(one_chip):
         lambda x, wg, wu, wd: ops.planned_ffn(x, wg, wu, wd, plan=plan,
                                               interpret=False),
         [(SEQ_BLOCK, d), (d, f), (d, f), (f, d)], one_chip)
+
+
+MAMBA = get_arch("mamba2-370m")
+SERVE_BATCH = 4      # the rows per task of the mamba2 serving cell
+
+
+def _stack_copies(text: str, shape: str):
+    """Instructions of the compiled program that copy an array of
+    ``shape`` (any layout, either order of its two minor axes)."""
+    dims = shape.split(",")
+    swapped = ",".join(dims[:-2] + [dims[-1], dims[-2]])
+    return [ln for ln in text.splitlines()
+            if re.search(rf"= f32\[({shape}|{swapped})\]\S* copy", ln)]
+
+
+@pytest.mark.parametrize("block_heads", [8, 16, 32])
+def test_ssd_decode_kernel_compiles_at_mamba2_heads(one_chip, block_heads):
+    """The in-place decode update at mamba2-370m's head tile, for each
+    block the head axis allows: a donated stack is updated without a
+    copy."""
+    L, B, H = MAMBA.num_layers, SERVE_BATCH, MAMBA.ssm_heads
+    N, P = MAMBA.ssm_state, MAMBA.ssm_head_dim
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda h, l, da, dt, x, b, c: ssd_decode_update(
+        h, l, da, dt, x, b, c, block_heads, interpret=False),
+        donate_argnums=(0,))
+    text = fn.lower(f32(L, B, H, P, N),
+                    jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                    f32(B, H), f32(B, H), f32(B, H, P), f32(B, N),
+                    f32(B, N)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not _stack_copies(text, f"{L},{B},{H},{P},{N}")
+
+
+def test_stacked_ssm_decode_epoch_copies_no_state(one_chip, monkeypatch):
+    """The serving cell's fused decode epoch at published widths (48
+    layers, 4 rows, 8 steps): the stacked float32 state is read and
+    written by the kernel alone, with no copy of the whole stack (the
+    swaps to the kernel's [.., P, N] are layout bitcasts).  The wrapper
+    is steered to the compiled kernel: on a host without a TPU it would
+    pick the interpreter."""
+    from repro.models import model as M
+    from repro.models.transformer import ssm_state_in_place
+    assert ssm_state_in_place(MAMBA)
+    monkeypatch.setattr(ops, "_interpret", lambda flag: False)
+    jax.clear_caches()
+    specs = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        M.decode_epoch_input_specs(MAMBA, SERVE_BATCH, 1152)[:4])
+    core = M.make_decode_epoch(MAMBA)
+    fn = jax.jit(lambda p, c, t, i: core(p, c, t, i, None, k=8, kv_len=256),
+                 donate_argnums=(1,))
+    text = fn.lower(*specs).compile().as_text()
+    jax.clear_caches()
+    assert "tpu_custom_call" in text
+    L, H = MAMBA.num_layers, MAMBA.ssm_heads
+    shape = f"{L},{SERVE_BATCH},{H},{MAMBA.ssm_state},{MAMBA.ssm_head_dim}"
+    assert f"f32[{shape}]" in text
+    assert not _stack_copies(text, shape)
 
 
 @pytest.mark.parametrize("arch", sorted(all_archs()))
