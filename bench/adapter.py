@@ -11,6 +11,8 @@ import os
 import sys
 from typing import Any, Dict, List
 
+import families
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(ROOT, "src")
 
@@ -32,37 +34,14 @@ def enable_compile_cache() -> str:
     return path
 
 
-# published config keys -> the program's ArchConfig fields, per family
-_LLAMA = {"hidden_size": "d_model", "num_hidden_layers": "num_layers",
-          "num_attention_heads": "num_heads",
-          "num_key_value_heads": "num_kv_heads",
-          "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-          "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-          "torch_dtype": "dtype"}
-_MAMBA2 = {"d_model": "d_model", "n_layer": "num_layers",
-           "vocab_size": "vocab_size", "d_state": "ssm_state",
-           "expand": "ssm_expand", "headdim": "ssm_head_dim",
-           "chunk_size": "ssm_chunk", "norm_epsilon": "norm_eps",
-           "torch_dtype": "dtype"}
-
-
 def arch_config(entry: Dict[str, Any]):
     """The program's ArchConfig for one model entry of a configuration
     file.  Widths and depth come from the entry's ``config`` (the keys
-    as run), the program's name for the model from ``program_name``."""
+    as run) through its family's map (``families/<family>.py``), the
+    program's name for the model from ``program_name``."""
     from repro.models.base import ArchConfig
-    cfg = entry["config"]
-    if entry["family"] == "llama":
-        kw = {f: cfg[k] for k, f in _LLAMA.items()}
-        kw.update(family="dense", head_dim=cfg["hidden_size"]
-                  // cfg["num_attention_heads"])
-    elif entry["family"] == "mamba2":
-        kw = {f: cfg[k] for k, f in _MAMBA2.items()}
-        kw.update(family="ssm", num_heads=0, num_kv_heads=0, d_ff=0,
-                  sub_quadratic=True)
-    else:
-        raise ValueError(f"unknown model family {entry['family']!r}")
-    return ArchConfig(name=entry["program_name"], **kw)
+    return ArchConfig(name=entry["program_name"],
+                      **families.of(entry).arch_fields(entry["config"]))
 
 
 class Served:

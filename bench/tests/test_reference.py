@@ -1,23 +1,24 @@
 """The float32 references against the program's own forward, at a tiny
 size on the CPU, with float32 weights: same seed, same equations."""
+import importlib
+import pkgutil
+
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from reference import llama, mamba2
+import families
 
-TINY = {
-    "llama": {"hidden_size": 128, "num_attention_heads": 4,
-              "num_key_value_heads": 2, "intermediate_size": 256,
-              "num_hidden_layers": 2, "vocab_size": 512,
-              "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
-              "torch_dtype": "float32"},
-    "mamba2": {"d_model": 128, "n_layer": 2, "vocab_size": 512,
-               "d_state": 16, "d_conv": 4, "expand": 2, "headdim": 32,
-               "chunk_size": 32, "norm_epsilon": 1e-5,
-               "torch_dtype": "float32"},
-}
+# every family with a module in bench/families/
+FAMILIES = sorted(m.name for m in pkgutil.iter_modules(families.__path__))
+
+
+def tiny(family):
+    return families.of({"family": family}).TINY
+
+
+def reference(family):
+    return importlib.import_module(f"reference.{family}")
 
 
 def program_logits(family, cfg, seed, tokens):
@@ -32,10 +33,10 @@ def program_logits(family, cfg, seed, tokens):
     return np.asarray(logits[..., :cfg["vocab_size"]], np.float32)
 
 
-@pytest.mark.parametrize("family", ["llama", "mamba2"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_reference_matches_program(family):
-    cfg = TINY[family]
-    ref = {"llama": llama, "mamba2": mamba2}[family]
+    cfg = tiny(family)
+    ref = reference(family)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 96), 0,
                                 cfg["vocab_size"])
     want = program_logits(family, cfg, 1234, tokens)
@@ -44,11 +45,11 @@ def test_reference_matches_program(family):
     assert err < 1e-4, err
 
 
-@pytest.mark.parametrize("family", ["llama", "mamba2"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_fp8_control_departs(family):
     """The control's logits lie measurably off the float32 ones."""
-    cfg = TINY[family]
-    ref = {"llama": llama, "mamba2": mamba2}[family]
+    cfg = tiny(family)
+    ref = reference(family)
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 64), 0,
                                 cfg["vocab_size"])
     hi = np.asarray(ref.logits(cfg, 99, tokens, start=32))
